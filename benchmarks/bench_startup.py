@@ -157,4 +157,6 @@ def main(argv=None) -> list:
 
 
 if __name__ == "__main__":
+    from repro.core.executable_cache import configure_compile_cache
+    configure_compile_cache()
     main()

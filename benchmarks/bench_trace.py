@@ -491,4 +491,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core.executable_cache import configure_compile_cache
+    configure_compile_cache()
     raise SystemExit(main())

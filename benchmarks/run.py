@@ -69,4 +69,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.executable_cache import configure_compile_cache
+    configure_compile_cache()
     main()

@@ -8,13 +8,10 @@ import sys
 sys.path.insert(0, "benchmarks")
 sys.path.insert(0, ".")
 
-import jax
-import jax.numpy as jnp
-
 from benchmarks.functions import catalog, example_args
 from repro.configs import get_config
 from repro.core import HydraRuntime, LMSpec
-from repro.models.programs import ModelProgram
+from repro.launch.serve import make_params
 
 
 def main():
@@ -34,10 +31,7 @@ def main():
 
     # 2. register an LM serving function (an assigned architecture)
     cfg = get_config("qwen2.5-3b").reduced()
-    prog = ModelProgram(cfg)
-    params = jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x,
-        prog.init(jax.random.PRNGKey(0)))
+    params = make_params(cfg, seed=0)
     rt.register_function("tenantA/lm",
                          LMSpec(cfg=cfg, params=params, max_seq=64, slots=1),
                          tenant="A")

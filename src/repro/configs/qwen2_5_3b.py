@@ -1,4 +1,5 @@
-"""qwen2.5-3b [dense] — GQA, QKV bias. [hf:Qwen/Qwen2.5-0.5B; hf]"""
+"""qwen2.5-3b [dense] — GQA, QKV bias, tied embeddings.
+[hf:Qwen/Qwen2.5-3B config.json]"""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -14,5 +15,7 @@ CONFIG = ArchConfig(
     activation="silu",
     qkv_bias=True,
     rope_theta=1000000.0,
-    source="hf:Qwen/Qwen2.5-0.5B; hf",
+    norm_eps=1e-6,
+    tie_embeddings=True,
+    source="hf:Qwen/Qwen2.5-3B config.json",
 )

@@ -146,12 +146,10 @@ class HydraCluster:
             # opted out (persist_executables=False) — matching the
             # platform-level default of zero-recompile restores across
             # boots
-            persist = xla_dir = None
+            persist = None
             if p.snapshot_dir and p.platform.persist_executables is not False:
                 persist = os.path.join(p.snapshot_dir, "executables")
-                xla_dir = os.path.join(p.snapshot_dir, "xla_cache")
-            self.exe_cache = ExecutableCache(persist_dir=persist,
-                                             xla_cache_dir=xla_dir)
+            self.exe_cache = ExecutableCache(persist_dir=persist)
         self.nodes: list[_NodeState] = []
         for i in range(p.n_nodes):
             plat_params = PlatformParams(**vars(p.platform))

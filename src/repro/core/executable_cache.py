@@ -19,43 +19,37 @@ import pickle
 import threading
 import time
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import Any, Callable, Optional
 
+import jax
+from jax.experimental.compilation_cache import compilation_cache as cc
 
-_XLA_CACHE_DIR: Optional[str] = None
+
+# fixed, in the checkout: the cache directory is part of what lets a later
+# process hit, so it is never derived from a temp name, pid or time
+DEFAULT_COMPILE_CACHE_DIR = str(
+    Path(__file__).resolve().parents[3] / ".jax_cache")
 
 
-def enable_persistent_compilation_cache(cache_dir: str) -> bool:
-    """Point jax's process-global persistent compilation cache at
-    ``cache_dir`` so XLA compilations are written to disk and replayed by
-    later processes (layered UNDER our ``serialize_executable`` payloads:
-    even when an entry's pickle is stale, the recompile becomes a cache
-    read instead of a real XLA run).
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory: ``JAX_COMPILATION_CACHE_DIR`` when set (JAX has
+    already read it; no other directory is set), else
+    ``DEFAULT_COMPILE_CACHE_DIR``. Entry points call this once, before
+    their first compile; importing this module configures nothing.
 
     The thresholds are lowered to cache everything — serverless programs
     are small and compile fast, exactly the entries the defaults skip.
-    Returns True when the cache is active; False (and stays inert) on jax
-    builds without the experimental API.
     """
-    global _XLA_CACHE_DIR
-    if _XLA_CACHE_DIR == cache_dir:
-        return True
-    try:
-        import jax
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc)
-        os.makedirs(cache_dir, exist_ok=True)
-        cc.set_cache_dir(cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except Exception:
-            pass  # knob absent on older jax: size floor stays default
-        _XLA_CACHE_DIR = cache_dir
-        return True
-    except Exception:
-        return False
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if not cache_dir:
+        cache_dir = DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    cc.reset_cache()   # a compile before this call would pin "no cache"
+    return cache_dir
 
 
 @dataclass
@@ -69,14 +63,11 @@ class CacheEntry:
 
 class ExecutableCache:
     def __init__(self, persist_dir: Optional[str] = None,
-                 shared: bool = True,
-                 xla_cache_dir: Optional[str] = None):
+                 shared: bool = True):
         """``shared=False`` emulates the per-context-JIT baseline (every
         registration compiles its own copy) for the Fig 4 experiment.
-
-        ``xla_cache_dir``: enable jax's persistent compilation cache at
-        this path (process-global; see
-        ``enable_persistent_compilation_cache``)."""
+        JAX's own persistent compilation cache sits under this one; it is
+        process-global and set up by ``configure_compile_cache``."""
         self._entries: dict[tuple, CacheEntry] = {}
         self._lock = threading.Lock()
         self.persist_dir = persist_dir
@@ -84,9 +75,6 @@ class ExecutableCache:
         self.total_compile_s = 0.0
         self.compiles = 0        # actual XLA compilations (not disk loads)
         self.disk_hits = 0       # executables deserialized from persist_dir
-        self.xla_cache_enabled = (
-            enable_persistent_compilation_cache(xla_cache_dir)
-            if xla_cache_dir else False)
         if persist_dir:
             os.makedirs(persist_dir, exist_ok=True)
 
@@ -170,5 +158,6 @@ class ExecutableCache:
                 "compiles": self.compiles,
                 "disk_hits": self.disk_hits,
                 "total_compile_s": self.total_compile_s,
-                "xla_cache_enabled": self.xla_cache_enabled,
+                "xla_cache_dir": jax.config.jax_compilation_cache_dir
+                or None,
             }

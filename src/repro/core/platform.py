@@ -128,15 +128,10 @@ class HydraPlatform:
         self.params = params or PlatformParams(**kw)
         p = self.params
         if exe_cache is None:
-            persist = xla_dir = None
+            persist = None
             if p.snapshot_dir and p.persist_executables_on():
                 persist = os.path.join(p.snapshot_dir, "executables")
-                # second persistence layer: jax's own compilation cache,
-                # so even entries without a serialized payload (or with a
-                # stale one) skip XLA on the next boot
-                xla_dir = os.path.join(p.snapshot_dir, "xla_cache")
-            exe_cache = ExecutableCache(persist_dir=persist,
-                                        xla_cache_dir=xla_dir)
+            exe_cache = ExecutableCache(persist_dir=persist)
         self.exe_cache = exe_cache
         self.metrics = Metrics(hist_max_samples=p.hist_max_samples)
         self._lock = threading.RLock()

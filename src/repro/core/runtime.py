@@ -10,6 +10,7 @@ the request path, converting runtime cold starts into arena cold starts.
 """
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -46,6 +47,35 @@ def registration_budget(spec, prog=None) -> tuple:
         cache = prog.cache_bytes(spec.slots, spec.max_seq)
         return tree_bytes(spec.params) + cache, cache
     raise TypeError(type(spec))
+
+
+def lm_decode_sample(prog, params, cache, tokens):
+    """Decode + greedy-sample step over all slots (the runtime donates
+    ``cache``)."""
+    logits, new_cache = prog.decode_step(params, cache, {"tokens": tokens})
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
+
+
+def lm_prefill_insert(prog, params, arena_cache, tokens, slot):
+    """Prefill ``tokens`` (1, prompt_len), then write the cache into row
+    ``slot`` of the arena cache slab (donated by the runtime)."""
+    prompt_len = tokens.shape[1]
+    logits, cache = prog.prefill(params, {"tokens": tokens})
+    out = dict(arena_cache)
+    for k in cache:
+        if k == "length":
+            out[k] = arena_cache[k].at[slot].set(prompt_len)
+        else:
+            dst, src = out[k], cache[k]
+            pad = [(0, a - b) for a, b in zip(dst.shape, src.shape)]
+            start = [jnp.int32(0)] * dst.ndim
+            start[1] = slot  # batch/slot axis is dim 1 (L, B, ...)
+            src = jnp.pad(src, pad).astype(dst.dtype)
+            # src padded to full slab shape; restrict to one slot row
+            src = jax.lax.slice_in_dim(src, 0, 1, axis=1)
+            out[k] = jax.lax.dynamic_update_slice(dst, src, tuple(start))
+    first_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return first_tok, out
 
 
 class HydraRuntime:
@@ -144,16 +174,11 @@ class HydraRuntime:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), spec.params)
         fkey = spec.family_key
 
-        # decode+greedy-sample fused step over all slots (cache donated)
-        def decode_sample(params, cache, tokens):
-            logits, new_cache = prog.decode_step(params, cache,
-                                                 {"tokens": tokens})
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_cache
-
         tok_spec = jax.ShapeDtypeStruct((B, 1), jnp.int32)
         entry_dec = self.exe_cache.get_or_compile(
             fkey + ("decode",),
-            lambda: jax.jit(decode_sample, donate_argnums=(1,)).lower(
+            lambda: jax.jit(functools.partial(lm_decode_sample, prog),
+                            donate_argnums=(1,)).lower(
                 params_spec, cache_specs, tok_spec),
             fid=fid)
 
@@ -184,33 +209,12 @@ class HydraRuntime:
         spec: LMSpec = func.spec
         prog: ModelProgram = func.prog
         key = spec.family_key + ("prefill", prompt_len)
-
-        def prefill_insert(params, arena_cache, tokens, slot):
-            """prefill (1, prompt_len) then write into the given slot of the
-            arena cache slab (donated)."""
-            logits, cache = prog.prefill(params, {"tokens": tokens})
-            out = dict(arena_cache)
-            for k in cache:
-                if k == "length":
-                    out[k] = arena_cache[k].at[slot].set(prompt_len)
-                else:
-                    dst, src = out[k], cache[k]
-                    pad = [(0, a - b) for a, b in zip(dst.shape, src.shape)]
-                    start = [jnp.int32(0)] * dst.ndim
-                    start[1] = slot  # batch/slot axis is dim 1 (L, B, ...)
-                    src = jnp.pad(src, pad).astype(dst.dtype)
-                    # src padded to full slab shape; restrict to one slot row
-                    src = jax.lax.slice_in_dim(src, 0, 1, axis=1)
-                    out[k] = jax.lax.dynamic_update_slice(
-                        dst, src, tuple(start))
-            first_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return first_tok, out
-
         cache_specs = prog.cache_specs(spec.slots, spec.max_seq)
         tok_spec = jax.ShapeDtypeStruct((1, prompt_len), jnp.int32)
         slot_spec = jax.ShapeDtypeStruct((), jnp.int32)
         entry = self.exe_cache.get_or_compile(
-            key, lambda: jax.jit(prefill_insert, donate_argnums=(1,)).lower(
+            key, lambda: jax.jit(functools.partial(lm_prefill_insert, prog),
+                                 donate_argnums=(1,)).lower(
                 func.params_spec, cache_specs, tok_spec, slot_spec),
             fid=func.fid)
         return entry.compiled

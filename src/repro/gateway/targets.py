@@ -92,11 +92,12 @@ class TargetAdapter:
         """Fleet compile counters summed over ``exe_caches()``:
         ``compiles`` (real XLA runs), ``disk_hits`` (serialized
         executables loaded), ``cache_hits`` (in-process entry reuse),
-        ``entries``, and whether jax's persistent compilation cache is
-        active. A warm fleet should show compiles == 0 after boot."""
+        ``entries``, and jax's persistent compilation cache directory
+        (None when off). A warm fleet should show compiles == 0 after
+        boot."""
         out = {"compiles": 0, "disk_hits": 0, "cache_hits": 0,
                "entries": 0, "total_compile_s": 0.0,
-               "xla_cache_enabled": False}
+               "xla_cache_dir": None}
         for cache in self.exe_caches():
             if cache is None:
                 continue
@@ -106,7 +107,7 @@ class TargetAdapter:
             out["cache_hits"] += s["hits"]
             out["entries"] += s["entries"]
             out["total_compile_s"] += s["total_compile_s"]
-            out["xla_cache_enabled"] |= bool(s.get("xla_cache_enabled"))
+            out["xla_cache_dir"] = s["xla_cache_dir"]
         return out
 
     def sample(self) -> dict:

@@ -4,8 +4,9 @@
 Grid = (B, num_kv_blocks); each instance processes ALL query heads of one
 sequence (the whole q row fits VMEM easily: Hq x hd). The KV axis is the
 innermost "arbitrary" dimension with the online-softmax state in VMEM
-scratch. Per-row valid lengths arrive as a scalar-prefetch operand (SMEM),
-which also lets fully-invalid KV blocks skip their compute.
+scratch. Per-row valid lengths and the sliding window arrive as
+scalar-prefetch operands (SMEM); the lengths also let fully-invalid KV
+blocks skip their compute, and a traced window compiles like a static one.
 """
 from __future__ import annotations
 
@@ -17,13 +18,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale: float, window, bk: int, nk: int, group: int):
+def _kernel(len_ref, win_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+            acc_scr, *, scale: float, windowed: bool, bk: int, nk: int,
+            group: int):
     b = pl.program_id(0)
     ki = pl.program_id(1)
     length = len_ref[b]
@@ -49,8 +49,8 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         kpos = ki * bk + jax.lax.broadcasted_iota(
             jnp.int32, (Hkv, group, bk), 2)
         mask = kpos < length
-        if window is not None:
-            mask &= kpos > length - 1 - window
+        if windowed:
+            mask &= kpos > length - 1 - win_ref[0]
         s = jnp.where(mask, s, NEG_INF)
         s = s.reshape(Hq, bk)
 
@@ -74,14 +74,18 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
-                     scale=None, interpret=False, block_k=256):
-    """q (B,Hq,hd), k/v cache (B,S,Hkv,hd), lengths (B,) -> (B,Hq,hd)."""
+                     scale=None, interpret=False, block_k=None):
+    """q (B,Hq,hd), k/v cache (B,S,Hkv,hd), lengths (B,) -> (B,Hq,hd).
+
+    ``window`` is None (full attention) or an int / int32 scalar, which may
+    be traced. ``block_k`` defaults to 256 keys for head dims up to 128 and
+    128 above: at hd 256 a 256-key block overflows the TPU's scoped VMEM."""
     B, Hq, hd = q.shape
     S, Hkv = k_cache.shape[1], k_cache.shape[2]
     group = Hq // Hkv
-    if not isinstance(window, (int, type(None))):
-        raise ValueError("Pallas path needs a static window")
     scale = scale if scale is not None else hd ** -0.5
+    if block_k is None:
+        block_k = 256 if hd <= 128 else 128
 
     bk = min(block_k, S)
     s_pad = math.ceil(S / bk) * bk
@@ -90,18 +94,22 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
         k_cache, v_cache = jnp.pad(k_cache, pad), jnp.pad(v_cache, pad)
     nk = s_pad // bk
 
-    kernel = functools.partial(_kernel, scale=scale, window=window,
+    kernel = functools.partial(_kernel, scale=scale,
+                               windowed=window is not None,
                                bk=bk, nk=nk, group=group)
+    win = jnp.asarray(0 if window is None else window, jnp.int32).reshape(1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, nk),
         in_specs=[
-            pl.BlockSpec((1, Hq, hd), lambda b, j, lens: (b, 0, 0)),
-            pl.BlockSpec((1, bk, Hkv, hd), lambda b, j, lens: (b, j, 0, 0)),
-            pl.BlockSpec((1, bk, Hkv, hd), lambda b, j, lens: (b, j, 0, 0)),
+            pl.BlockSpec((1, Hq, hd), lambda b, j, lens, w: (b, 0, 0)),
+            pl.BlockSpec((1, bk, Hkv, hd),
+                         lambda b, j, lens, w: (b, j, 0, 0)),
+            pl.BlockSpec((1, bk, Hkv, hd),
+                         lambda b, j, lens, w: (b, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Hq, hd), lambda b, j, lens: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, hd), lambda b, j, lens, w: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hq, 1), jnp.float32),
             pltpu.VMEM((Hq, 1), jnp.float32),
@@ -112,8 +120,8 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), win, q, k_cache, v_cache)
     return out

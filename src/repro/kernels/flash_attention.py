@@ -1,7 +1,9 @@
 """Flash attention Pallas TPU kernel (prefill/train path).
 
 Online-softmax blocked attention with GQA, causal masking and an optional
-static sliding window. Grid = (B, Hq, num_q_blocks, num_kv_blocks); the KV
+sliding window. The window arrives as a scalar-prefetch operand (SMEM), so
+one compiled kernel serves every layer of a scanned stack whatever its
+window. Grid = (B, Hq, num_q_blocks, num_kv_blocks); the KV
 axis is the innermost ("arbitrary") dimension and the running (m, l, acc)
 state lives in VMEM scratch across KV iterations — the canonical TPU
 flash-attention schedule (HBM->VMEM tiles, MXU for the two matmuls).
@@ -19,13 +21,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale: float, causal: bool, window, bq: int, bk: int,
+def _kernel(win_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+            scale: float, causal: bool, windowed: bool, bq: int, bk: int,
             s_orig: int, nk: int):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -48,8 +48,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     mask = kpos < s_orig
     if causal:
         mask &= kpos <= qpos
-    if window is not None:
-        mask &= kpos > qpos - window
+    if windowed:
+        mask &= kpos > qpos - win_ref[0]
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_scr[...]                                  # (bq, 1)
@@ -71,14 +71,14 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
                     interpret=False, block_q=128, block_k=128):
-    """q (B,S,Hq,hd), k/v (B,S,Hkv,hd) -> (B,S,Hq,hd)."""
+    """q (B,S,Hq,hd), k/v (B,S,Hkv,hd) -> (B,S,Hq,hd).
+
+    ``window`` is None (full attention) or an int / int32 scalar, which may
+    be traced."""
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
     assert Hq % Hkv == 0
     group = Hq // Hkv
-    if not isinstance(window, (int, type(None))):
-        raise ValueError("Pallas path needs a static window; use the ref "
-                         "path for traced per-layer windows")
     scale = scale if scale is not None else hd ** -0.5
 
     bq = min(block_q, max(8, S))
@@ -94,30 +94,36 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
     nq, nk = s_pad // bq, s_pad // bk
 
     kernel = functools.partial(
-        _kernel, scale=scale, causal=causal, window=window,
+        _kernel, scale=scale, causal=causal, windowed=window is not None,
         bq=bq, bk=bk, s_orig=S, nk=nk)
+    win = jnp.asarray(0 if window is None else window, jnp.int32).reshape(1)
 
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j, w: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bk, hd),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
+                         lambda b, h, i, j, w, g=group: (b, h // g, j, 0)),
             pl.BlockSpec((1, 1, bk, hd),
-                         lambda b, h, i, j, g=group: (b, h // g, j, 0)),
+                         lambda b, h, i, j, w, g=group: (b, h // g, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, s_pad, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd),
+                               lambda b, h, i, j, w: (b, h, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, s_pad, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qt, kt, vt)
+    )(win, qt, kt, vt)
     out = out[:, :, :S, :]
     return jnp.moveaxis(out, 1, 2)

@@ -1,44 +1,41 @@
 """jit'd public wrappers for the Pallas kernels.
 
 Dispatch policy (``kernel_mode()``):
-  * ``auto``      — Pallas kernel on TPU, jnp reference elsewhere (CPU dry-run
-                    must see real HLO FLOPs, not an opaque callback).
-  * ``pallas``    — force the compiled Pallas kernel.
+  * ``auto``      — compiled Pallas kernel on TPU, jnp reference elsewhere
+                    (CPU dry-run must see real HLO FLOPs, not an opaque
+                    callback).
   * ``interpret`` — Pallas kernel in interpret mode (CPU correctness tests).
-  * ``ref``       — force the pure-jnp oracle.
+  * ``ref``       — force the pure-jnp oracle (the float32 reference).
+
+Only ``set_kernel_mode`` changes the mode: nothing in the environment can
+route a TPU run to the reference.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
 from repro.kernels import ref as _ref
 
-_MODE_ENV = "REPRO_KERNEL_MODE"
-_mode_override: str | None = None
+_MODES = ("auto", "interpret", "ref")
+_mode = "auto"
 
 
-def set_kernel_mode(mode: str | None) -> None:
-    global _mode_override
-    assert mode in (None, "auto", "pallas", "interpret", "ref"), mode
-    _mode_override = mode
+def set_kernel_mode(mode: str) -> None:
+    global _mode
+    if mode not in _MODES:
+        raise ValueError(f"kernel mode {mode!r} not in {_MODES}")
+    _mode = mode
 
 
 def kernel_mode() -> str:
-    if _mode_override is not None:
-        return _mode_override
-    return os.environ.get(_MODE_ENV, "auto")
+    return _mode
 
 
 def _use_pallas() -> tuple[bool, bool]:
     """-> (use_kernel, interpret)"""
-    mode = kernel_mode()
-    if mode == "pallas":
-        return True, False
-    if mode == "interpret":
+    if _mode == "interpret":
         return True, True
-    if mode == "ref":
+    if _mode == "ref":
         return False, False
     return jax.default_backend() == "tpu", False
 

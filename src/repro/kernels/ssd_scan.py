@@ -17,11 +17,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
-
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, y_ref, sf_ref,
-            state_scr, *, nc: int, chunk: int):
+def _kernel(x_ref, dt_ref, dtc_ref, a_ref, b_ref, c_ref, s0_ref, y_ref,
+            sf_ref, state_scr, *, nc: int, chunk: int):
+    h = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -29,19 +28,26 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, y_ref, sf_ref,
         state_scr[...] = s0_ref[0, 0].astype(jnp.float32)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (chunk, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (1, chunk)
-    A = a_ref[0].astype(jnp.float32)             # scalar
+    A = a_ref[h]                                 # scalar (SMEM)
+    a_row = dt_ref[0, 0].astype(jnp.float32) * A     # (1, chunk) log-decay
+    dt_col = dtc_ref[0, 0].astype(jnp.float32)       # (chunk, 1)
+    a_col = dt_col * A
     Bm = b_ref[0].astype(jnp.float32)            # (chunk, N)
     Cm = c_ref[0].astype(jnp.float32)            # (chunk, N)
 
-    a = dt[0] * A                                # (chunk,) log-decay
-    a_cs = jnp.cumsum(a)                         # (chunk,)
-    seg = a_cs[:, None] - a_cs[None, :]          # (l, s)
-    tril = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(tril, jnp.exp(seg), 0.0)
+    # in-chunk cumsum as masked sums (no cumsum lowering on Mosaic), in
+    # both layouts so no vector is ever transposed
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = row >= col
+    cs_col = jnp.sum(jnp.where(tril, a_row, 0.0), axis=1,
+                     keepdims=True)              # (chunk, 1)
+    cs_row = jnp.sum(jnp.where(row <= col, a_col, 0.0), axis=0,
+                     keepdims=True)              # (1, chunk)
+    total = jnp.sum(a_row, axis=1, keepdims=True)    # (1, 1)
+    L = jnp.where(tril, jnp.exp(cs_col - cs_row), 0.0)  # (l, s)
 
-    dtx = dt[0][:, None] * x                     # (chunk, P)
+    dtx = dt_col * x                             # (chunk, P)
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     y_diag = jax.lax.dot_general(CB * L, dtx, (((1,), (0,)), ((), ())),
@@ -50,14 +56,14 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref, y_ref, sf_ref,
     state = state_scr[...]                       # (P, N)
     y_off = jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    y_off = y_off * jnp.exp(a_cs)[:, None]       # (chunk, P)
+    y_off = y_off * jnp.exp(cs_col)              # (chunk, P)
     y_ref[0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
-    decay_tail = jnp.exp(a_cs[-1] - a_cs)        # (chunk,)
+    decay_tail = jnp.exp(total - cs_col)         # (chunk, 1)
     new_contrib = jax.lax.dot_general(
-        dtx, Bm * decay_tail[:, None], (((0,), (0,)), ((), ())),
+        dtx, Bm * decay_tail, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)      # (P, N)
-    state_scr[...] = state * jnp.exp(a_cs[-1]) + new_contrib
+    state_scr[...] = state * jnp.exp(total) + new_contrib
 
     @pl.when(ci == nc - 1)
     def _finish():
@@ -82,6 +88,7 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk=64, init_state=None,
 
     xt = jnp.moveaxis(x, 2, 1)                   # (B, H, S, P)
     dtt = jnp.moveaxis(dt, 2, 1)[:, :, None, :]  # (B, H, 1, S)
+    dtc = jnp.moveaxis(dt, 2, 1)[..., None]       # (B, H, S, 1)
     s0 = (jnp.zeros((B, H, P, N), jnp.float32) if init_state is None
           else init_state.astype(jnp.float32))
 
@@ -92,7 +99,8 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk=64, init_state=None,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, 0, c)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # A: whole (H,) in SMEM
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
@@ -106,10 +114,10 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk=64, init_state=None,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xt, dtt, A, Bm, Cm, s0)
+    )(xt, dtt, dtc, A.astype(jnp.float32), Bm, Cm, s0)
     y = jnp.moveaxis(y, 1, 2)[:, :S]
     if return_state:
         return y, sf

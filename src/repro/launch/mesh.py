@@ -4,29 +4,12 @@ from __future__ import annotations
 
 import jax
 
-# jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist in
-# newer JAX releases; the pinned 0.4.x has neither. All axes default to
-# Auto there anyway, so omitting the kwarg is semantically identical.
-AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    if AXIS_TYPE is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(AXIS_TYPE.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def make_abstract_mesh(axis_shapes, axis_names):
-    """``jax.sharding.AbstractMesh`` across the signature change: newer JAX
-    takes ``(shape, names)``; 0.4.x takes one ``((name, size), ...)`` tuple."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_shapes),
-                                         tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_shapes)))
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler from the ``shard`` annotations)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -32,11 +32,15 @@ Other knobs: ``--runtime-budget-gb`` caps each runtime's memory budget,
 ``--snapshot-dir`` enables sandbox snapshot/evict/restore (and is required
 for cluster migration).
 
-  PYTHONPATH=src python -m repro.launch.serve --archs qwen2.5-3b,mamba2-780m \\
-      --tenants 4 --requests 32 --slots 4 --pool 2
+The closed-loop driver serves each architecture at its published widths;
+``--reduced`` swaps in the tiny same-family config for CPU runs:
 
-  PYTHONPATH=src python -m repro.launch.serve --tenants 4 --requests 16 \\
-      --nodes 2 --pool 1
+  PYTHONPATH=src python -m repro.launch.serve --reduced \\
+      --archs qwen2.5-3b,mamba2-780m --tenants 4 --requests 32 --slots 4 \\
+      --pool 2
+
+  PYTHONPATH=src python -m repro.launch.serve --reduced --tenants 4 \\
+      --requests 16 --nodes 2 --pool 1
 
   PYTHONPATH=src python -m repro.launch.serve --gateway \\
       --trace-file benchmarks/data/azure_sample.csv --compress 60
@@ -102,11 +106,17 @@ def maybe_reexec_tcmalloc(argv) -> None:
 
 
 def make_params(cfg, seed: int = 0):
+    """bf16 serving weights generated on the device from ``seed``. One jit
+    casts each float32 leaf where it is generated, so the float32 tree is
+    never held whole (at published widths it would not fit beside a
+    second tenant)."""
     prog = ModelProgram(cfg)
-    params = prog.init(jax.random.PRNGKey(seed))
-    return jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x,
-        params)
+
+    def init(key):
+        return jax.tree.map(
+            lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32
+            else x, prog.init(key))
+    return jax.jit(init)(jax.random.PRNGKey(seed))
 
 
 def build_target(args, arena_ttl_s=None):
@@ -131,11 +141,15 @@ def build_target(args, arena_ttl_s=None):
     return HydraRuntime(memory_budget_bytes=budget, **ttl)
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--archs", default="qwen2.5-3b",
                     help="comma-separated model architectures to serve "
                          "(closed-loop LM driver)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve each architecture's tiny same-family "
+                         "config (d_model 64) instead of its published "
+                         "widths; for CPU runs and tests")
     ap.add_argument("--tenants", type=int, default=2,
                     help="tenants per architecture (each gets its own "
                          "registered function)")
@@ -238,6 +252,11 @@ def main(argv=None):
                          "them with a fleet snapshot as JSONL under DIR "
                          "on each anomaly (SLO drop, OOM give-up, "
                          "migration requeue; gateway mode)")
+    return ap
+
+
+def main(argv=None):
+    ap = build_parser()
     args = ap.parse_args(argv)
 
     if args.tcmalloc:
@@ -302,7 +321,9 @@ def main(argv=None):
     fids = []
     for t in range(args.tenants):
         arch = archs[t % len(archs)]
-        cfg = get_config(arch).reduced()
+        cfg = get_config(arch)
+        if args.reduced:
+            cfg = cfg.reduced()
         spec = LMSpec(cfg=cfg, params=make_params(cfg, seed=t),
                       max_seq=args.max_seq, slots=args.slots)
         fid = f"tenant{t}/{arch}"
@@ -440,6 +461,8 @@ def run_gateway(args) -> dict:
         target.shutdown()
 
     summary = res.summary()
+    summary["submitted"] = extras["submitted"]
+    summary["errors"] = extras["errors"]
     served = summary["requests"]
     print(f"[gateway] served {served}/{extras['submitted']} requests in "
           f"{extras['wall_s']:.1f}s wall ({extras['registered']} functions "
@@ -529,4 +552,6 @@ def emit_calibration(path, platform, runtimes) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.core.executable_cache import configure_compile_cache
+    configure_compile_cache()
     main()

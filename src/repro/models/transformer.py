@@ -62,20 +62,20 @@ def init_params(rng, cfg):
 
 
 def layer_windows(cfg, static: bool = False):
-    """Per-layer attention window (int32). GLOBAL_WINDOW = full attention.
+    """Per-layer attention window (int32), or None when no layer of ``cfg``
+    has a sliding window (the kernels then compile without a window mask).
+    GLOBAL_WINDOW marks the full-attention layers of a windowed stack.
 
     ``static=True`` (unrolled paths) returns a numpy array so each layer's
-    window is a Python int at trace time — enabling windowed KV-cache reads
-    and static-window Pallas kernels."""
+    window is a Python int at trace time — enabling windowed KV-cache
+    reads."""
     import numpy as np
-    L = cfg.n_layers
     if cfg.sliding_window is None:
-        out = np.full((L,), GLOBAL_WINDOW, np.int32)
-    else:
-        idx = np.arange(L)
-        is_global = (idx + 1) % (cfg.global_every or L + 1) == 0
-        out = np.where(is_global, GLOBAL_WINDOW,
-                       cfg.sliding_window).astype(np.int32)
+        return None
+    idx = np.arange(cfg.n_layers)
+    is_global = (idx + 1) % (cfg.global_every or cfg.n_layers + 1) == 0
+    out = np.where(is_global, GLOBAL_WINDOW,
+                   cfg.sliding_window).astype(np.int32)
     return out if static else jnp.asarray(out)
 
 

@@ -12,7 +12,6 @@ if _REPO_ROOT not in sys.path:
     sys.path.insert(0, _REPO_ROOT)
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 
 
@@ -22,7 +21,5 @@ def rng():
 
 
 def bf16_params(prog, seed: int = 0):
-    params = prog.init(jax.random.PRNGKey(seed))
-    return jax.tree.map(
-        lambda x: x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x,
-        params)
+    from repro.launch.serve import make_params
+    return make_params(prog.cfg, seed)

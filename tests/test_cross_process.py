@@ -16,8 +16,10 @@ import subprocess
 import sys
 
 import jax.numpy as jnp
+import pytest
 
 from repro.core import CallableSpec, HydraPlatform
+from repro.core.executable_cache import DEFAULT_COMPILE_CACHE_DIR
 
 MB = 1 << 20
 REPO_SRC = os.path.join(os.path.dirname(os.path.dirname(
@@ -30,7 +32,9 @@ import json, sys
 import jax
 import jax.numpy as jnp
 from repro.core import CallableSpec, HydraPlatform
+from repro.core.executable_cache import configure_compile_cache
 
+configure_compile_cache()
 meta = json.load(open(sys.argv[1]))
 
 def fn(params, args):
@@ -90,6 +94,7 @@ def test_restore_in_fresh_process_zero_recompiles(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla")
     proc = subprocess.run([sys.executable, str(child), str(meta_path)],
                           capture_output=True, text=True, timeout=300,
                           env=env)
@@ -102,43 +107,53 @@ def test_restore_in_fresh_process_zero_recompiles(tmp_path):
     # cache persisted by the PARENT process
     assert stats["compiles"] == 0
     assert stats["disk_hits"] >= 1
-    # snapshot_dir also switched on jax's persistent compilation cache
-    # (the layer under serialize_executable) in both processes
-    assert stats["xla_cache_enabled"] is True
+    # jax's persistent compilation cache comes from the environment, as
+    # the child's entry point configured it: never from snapshot_dir
+    assert stats["xla_cache_dir"] == str(tmp_path / "xla")
+    assert not (tmp_path / "xla_cache").exists()
 
 
 # ---------------------------------------------------------------------------
 XLA_CACHE_CHILD = r"""
-import sys
 import jax
 import jax.numpy as jnp
-from repro.core.executable_cache import enable_persistent_compilation_cache
+from repro.core.executable_cache import configure_compile_cache
 
-assert enable_persistent_compilation_cache(sys.argv[1])
+cache_dir = configure_compile_cache()
 out = jax.jit(lambda x: (x * 3.0 + 1.0).sum())(jnp.ones((257,), jnp.float32))
+print(cache_dir)
 print(float(out))
 """
 
 
-def test_xla_persistent_cache_reused_by_fresh_process(tmp_path):
+@pytest.mark.parametrize("source", ["env", "default"])
+def test_xla_persistent_cache_reused_by_fresh_process(tmp_path, source):
     """The layer UNDER our serialize_executable payloads: jax's persistent
-    compilation cache. The first process writes its XLA compilation to the
-    shared directory; a second, fresh process compiling the same program
-    replays it from disk instead of re-running XLA — no new cache entries
-    appear. (Run in subprocesses because the cache dir is process-global.)"""
-    cache_dir = tmp_path / "xla"
+    compilation cache, configured by an entry point. Its directory is
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else one fixed path in the
+    checkout. The first process writes its XLA compilation there; a
+    second, fresh process compiling the same program replays it from
+    disk — no new cache entries appear. (Subprocesses: the cache is
+    process-global.)"""
     script = tmp_path / "xla_child.py"
     script.write_text(XLA_CACHE_CHILD)
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    if source == "env":
+        cache_dir = str(tmp_path / "xla")
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    else:
+        cache_dir = DEFAULT_COMPILE_CACHE_DIR
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
 
     def run_once():
         proc = subprocess.run(
-            [sys.executable, str(script), str(cache_dir)],
+            [sys.executable, str(script)],
             capture_output=True, text=True, timeout=300, env=env)
         assert proc.returncode == 0, proc.stderr[-2000:]
-        assert proc.stdout.strip().splitlines()[-1] == "1028.0"
+        lines = proc.stdout.strip().splitlines()
+        assert lines[-2:] == [cache_dir, "1028.0"]
         return sorted(os.listdir(cache_dir))
 
     first = run_once()

@@ -5,6 +5,7 @@ snapshot migration), SimResult-schema recording, the sim-vs-live
 validation harness, and the gateway -> calibration -> sim round trip."""
 import time
 
+import jax
 import pytest
 
 from repro.core.calibrate import (CALIBRATABLE_FIELDS, apply_calibration,
@@ -77,7 +78,11 @@ def test_replay_emits_simresult_schema_and_full_accounting():
     exe = extras["exe_cache"]
     assert exe["entries"] >= 1
     assert {"compiles", "disk_hits", "cache_hits",
-            "xla_cache_enabled"} <= set(exe)
+            "xla_cache_dir"} <= set(exe)
+    # the persistent compile cache is process-global, set up only by an
+    # entry point (configure_compile_cache), never by building a stack
+    assert exe["xla_cache_dir"] == (jax.config.jax_compilation_cache_dir
+                                    or None)
     assert {"reuse", "zeroed"} == set(extras["slab"])
 
 

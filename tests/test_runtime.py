@@ -11,6 +11,7 @@ from repro.core import (CallableSpec, ContinuousBatcher, ExecutableCache,
                         FunctionNotRegisteredError, HydraOOMError,
                         HydraRuntime, LMSpec, MemoryBudget)
 from repro.core.arena import ArenaPool
+from repro.kernels.ops import set_kernel_mode
 from repro.models.programs import ModelProgram
 
 from conftest import bf16_params
@@ -177,6 +178,30 @@ def test_lm_generate_deterministic_and_warm():
         assert rt.metrics.counters["arena.warm"] >= 1
     finally:
         rt.shutdown()
+
+
+@pytest.mark.parametrize("arch", ["qwen2.5-3b", "gemma3-1b"])
+def test_lm_registers_and_generates_through_pallas_kernels(arch):
+    """Registration AOT-compiles decode through the Pallas kernels (here in
+    interpret mode; compiled on a TPU). Each layer's window must reach the
+    kernels as a static None or, for gemma3's scanned local:global
+    pattern, as a runtime operand. Tokens equal the jnp reference path's."""
+    cfg = get_config(arch).reduced()
+    params = bf16_params(ModelProgram(cfg))
+    prompt = list(range(3, 15))   # longer than gemma3's reduced window (8)
+    toks = {}
+    for mode in ("interpret", "ref"):
+        set_kernel_mode(mode)
+        rt = make_rt(memory_budget_bytes=2 << 30)
+        try:
+            rt.register_function("lm", LMSpec(cfg=cfg, params=params,
+                                              max_seq=64, slots=2))
+            toks[mode] = rt.generate("lm", prompt, max_new_tokens=6)
+        finally:
+            rt.shutdown()
+            set_kernel_mode("auto")
+    assert len(toks["interpret"]) == 6
+    assert toks["interpret"] == toks["ref"]
 
 
 def test_continuous_batcher_matches_single_path():

@@ -42,8 +42,7 @@ def test_param_rules_no_duplicate_axes():
 def test_kv_replicated_when_heads_not_divisible():
     """gemma3 has 1 KV head: its wk/wv must be replicated under TP-16
     (production mesh geometry via AbstractMesh — no devices needed)."""
-    from repro.launch.mesh import make_abstract_mesh
-    m = make_abstract_mesh((16, 16), ("data", "model"))
+    m = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("gemma3-1b")
     params = jax.eval_shape(
         lambda: tf.init_params(jax.random.PRNGKey(0), cfg))
