@@ -1,0 +1,27 @@
+"""Kernel: ssd_scan's share of its roofline in the traced window's
+prefills: the chunked scan of the real prompt (``costs.ssd_scan``), least
+time the larger of operations over the peak rate and bytes over the HBM
+bandwidth, summed over calls, over the summed kernel time, in percent."""
+from chipbench import costs, kernels
+
+
+def read(run):
+    chunk = run.cell.config["layer_defaults"]["chunk_size"]
+    least = spent = mem = 0.0
+    for m in run.step_modules("prefill"):
+        if not m.prompt:
+            continue
+        for op in m.ops:
+            if kernels.kernel_of(op.name) != "ssd_scan":
+                continue
+            s = kernels.shapes(op.name)
+            per, bound = costs.least_time_s(
+                *costs.ssd_scan(m.prompt, s["H"], s["P"], s["N"],
+                                min(chunk, m.prompt)), run.peak)
+            least += s["B"] * per
+            mem += s["B"] * per if bound == "memory" else 0.0
+            spent += op.dur * 1e-9
+    if spent == 0.0:
+        return None
+    return {"value": 100.0 * least / spent,
+            "bound": "memory" if mem >= least / 2 else "compute"}
