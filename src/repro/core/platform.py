@@ -63,7 +63,7 @@ from repro.core.errors import (FunctionNotRegisteredError, HydraError,
 from repro.core.executable_cache import ExecutableCache
 from repro.core.metrics import Metrics
 from repro.core.runtime import GB, HydraRuntime, registration_budget
-from repro.core.tracing import NULL_TRACE
+from repro.core.tracing import NULL_TRACE, phase
 from repro.ft import checkpoint as ckpt
 
 
@@ -148,7 +148,7 @@ class HydraPlatform:
     # ------------------------------------------------------------------
     def _boot_runtime(self) -> HydraRuntime:
         p = self.params
-        with self.metrics.timeit("runtime_boot_s"):
+        with phase("platform.boot"), self.metrics.timeit("runtime_boot_s"):
             rt = HydraRuntime(memory_budget_bytes=p.runtime_budget_bytes,
                               arena_ttl_s=p.arena_ttl_s,
                               n_workers=p.n_workers,

@@ -27,7 +27,8 @@ from repro.core.executable_cache import ExecutableCache
 from repro.core.metrics import Metrics
 from repro.core.registry import (CallableSpec, Function, FunctionRegistry,
                                  LMSpec)
-from repro.core.tracing import NULL_TRACE, trace_now
+from repro.core.tracing import (NULL_TRACE, install_gc_phase, phase,
+                                 trace_now)
 from repro.models.programs import ModelProgram
 
 GB = 1 << 30
@@ -78,6 +79,14 @@ def lm_prefill_insert(prog, params, arena_cache, tokens, slot):
     return first_tok, out
 
 
+def _bind(step, prog):
+    """``step`` with ``prog`` bound, keeping ``step``'s name: the compiled
+    module is then ``jit_<name>`` in a profile, not ``jit__unknown``."""
+    bound = functools.partial(step, prog)
+    bound.__name__ = step.__name__
+    return bound
+
+
 class HydraRuntime:
     def __init__(self, *,
                  memory_budget_bytes: int = 2 * GB,  # paper: 2 GB per runtime
@@ -86,6 +95,7 @@ class HydraRuntime:
                  executable_cache: Optional[ExecutableCache] = None,
                  janitor: bool = True,
                  hist_max_samples: Optional[int] = None):
+        install_gc_phase()
         self.metrics = Metrics(hist_max_samples=hist_max_samples)
         self.budget = MemoryBudget(memory_budget_bytes, name="hydra")
         self.registry = FunctionRegistry()
@@ -177,7 +187,7 @@ class HydraRuntime:
         tok_spec = jax.ShapeDtypeStruct((B, 1), jnp.int32)
         entry_dec = self.exe_cache.get_or_compile(
             fkey + ("decode",),
-            lambda: jax.jit(functools.partial(lm_decode_sample, prog),
+            lambda: jax.jit(_bind(lm_decode_sample, prog),
                             donate_argnums=(1,)).lower(
                 params_spec, cache_specs, tok_spec),
             fid=fid)
@@ -206,6 +216,11 @@ class HydraRuntime:
     def _lm_prefill_exe(self, func: Function, prompt_len: int):
         """Exact-length prefill program, AOT-compiled + cached on first use
         of this prompt length (production would use length buckets)."""
+        return self._lm_prefill_entry(func, prompt_len).compiled
+
+    def _lm_prefill_entry(self, func: Function, prompt_len: int):
+        """The prefill program's cache entry; ``hits == 0`` on the call
+        that built it."""
         spec: LMSpec = func.spec
         prog: ModelProgram = func.prog
         key = spec.family_key + ("prefill", prompt_len)
@@ -213,11 +228,11 @@ class HydraRuntime:
         tok_spec = jax.ShapeDtypeStruct((1, prompt_len), jnp.int32)
         slot_spec = jax.ShapeDtypeStruct((), jnp.int32)
         entry = self.exe_cache.get_or_compile(
-            key, lambda: jax.jit(functools.partial(lm_prefill_insert, prog),
+            key, lambda: jax.jit(_bind(lm_prefill_insert, prog),
                                  donate_argnums=(1,)).lower(
                 func.params_spec, cache_specs, tok_spec, slot_spec),
             fid=func.fid)
-        return entry.compiled
+        return entry
 
     # ------------------------------------------------------------------
     # Invocation (paper Listing 1)
@@ -311,7 +326,8 @@ class HydraRuntime:
     def _janitor_loop(self):
         while not self._shutdown.is_set():
             time.sleep(min(1.0, self.arena_pool.ttl_s / 4))
-            self.arena_pool.evict_idle()
+            with phase("runtime.evict_idle"):
+                self.arena_pool.evict_idle()
 
     # ------------------------------------------------------------------
     def stats(self) -> dict:

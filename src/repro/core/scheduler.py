@@ -1,6 +1,12 @@
 """Request scheduling: continuous batching for decode (the high-density
 serving analog of the paper's many-isolates-per-runtime) and per-tenant
 token buckets (the cgroup CPU-share analog, §3.7).
+
+``ContinuousBatcher.step`` is on the profiler's clock as host phases
+(``core/tracing.py``): ``hydra.lm.step`` holds one ``hydra.lm.admit`` per
+admitted request, one ``hydra.lm.decode`` (the decode dispatch) and one
+``hydra.lm.sync`` (the token readback and retirement). No lock is held
+across any of them: ``submit`` only ever waits for a list append.
 """
 from __future__ import annotations
 
@@ -10,6 +16,8 @@ from concurrent.futures import Future
 from dataclasses import dataclass, field
 
 import jax.numpy as jnp
+
+from repro.core.tracing import phase
 
 
 class TokenBucket:
@@ -39,6 +47,7 @@ class _Request:
     prompt: list
     max_new: int
     future: Future
+    submitted: float           # perf_counter at submit
     slot: int = -1
     emitted: list = field(default_factory=list)
 
@@ -69,8 +78,9 @@ class ContinuousBatcher:
 
     def submit(self, prompt_tokens, max_new: int) -> Future:
         fut: Future = Future()
+        req = _Request(list(prompt_tokens), max_new, fut, time.perf_counter())
         with self._lock:
-            self.pending.append(_Request(list(prompt_tokens), max_new, fut))
+            self.pending.append(req)
         return fut
 
     # ------------------------------------------------------------------
@@ -80,33 +90,40 @@ class ContinuousBatcher:
                 req = self.pending.pop(0)
             slot = self.free.pop(0)
             req.slot = slot
-            prompt = jnp.asarray(req.prompt, jnp.int32).reshape(1, -1)
-            exe = self.rt._lm_prefill_exe(self.func, prompt.shape[1])
-            tok, self.cache = exe(self.spec.params, self.cache, prompt,
-                                  jnp.int32(slot))
-            req.emitted.append(int(tok[0]))
-            self.cur_tok = self.cur_tok.at[slot, 0].set(int(tok[0]))
+            waited_us = int((time.perf_counter() - req.submitted) * 1e6)
+            with phase("lm.admit", slot=slot, prompt_len=len(req.prompt),
+                       waited_us=waited_us) as sp:
+                prompt = jnp.asarray(req.prompt, jnp.int32).reshape(1, -1)
+                entry = self.rt._lm_prefill_entry(self.func, prompt.shape[1])
+                sp.set_metadata(compiled=int(entry.hits == 0))
+                tok, self.cache = entry.compiled(
+                    self.spec.params, self.cache, prompt, jnp.int32(slot))
+                req.emitted.append(int(tok[0]))
+                self.cur_tok = self.cur_tok.at[slot, 0].set(int(tok[0]))
             self.active[slot] = req
 
     def step(self) -> int:
         """One scheduler tick: admit, then decode every active slot."""
-        self._admit()
-        if not self.active:
-            return 0
-        tok, self.cache = self.func.entry["decode"](
-            self.spec.params, self.cache, self.cur_tok)
-        self.cur_tok = tok.reshape(self.slots, 1)
-        self.steps += 1
-        done = []
-        for slot, req in self.active.items():
-            req.emitted.append(int(tok[slot]))
-            if len(req.emitted) >= req.max_new:
-                done.append(slot)
-        for slot in done:
-            req = self.active.pop(slot)
-            self.free.append(slot)
-            req.future.set_result(req.emitted)
-        return len(self.active) + len(done)
+        with phase("lm.step"):
+            self._admit()
+            if not self.active:
+                return 0
+            with phase("lm.decode", active=len(self.active)):
+                tok, self.cache = self.func.entry["decode"](
+                    self.spec.params, self.cache, self.cur_tok)
+                self.cur_tok = tok.reshape(self.slots, 1)
+            self.steps += 1
+            done = []
+            with phase("lm.sync", tokens=len(self.active)):
+                for slot, req in self.active.items():
+                    req.emitted.append(int(tok[slot]))
+                    if len(req.emitted) >= req.max_new:
+                        done.append(slot)
+                for slot in done:
+                    req = self.active.pop(slot)
+                    self.free.append(slot)
+                    req.future.set_result(req.emitted)
+            return len(self.active) + len(done)
 
     def run_until_done(self, max_steps: int = 10_000):
         while (self.active or self.pending) and max_steps > 0:

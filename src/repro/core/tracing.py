@@ -23,9 +23,25 @@ Phases are disjoint intervals inside the request window, so they admit
 a conservation invariant: span lengths plus the uncovered gaps
 (``unattributed``) equal end-to-end latency exactly, modulo measured
 ``overlap`` (expected ~0; asserted small by tests and the CI
-trace-smoke check). Timestamps all come from ``trace_now``
-(``time.perf_counter``) on every thread, so cross-thread spans share
-one clock.
+trace-smoke check). The recorded timestamps all come from
+``trace_now`` (``time.perf_counter``) on every thread, so cross-thread
+spans share one host clock.
+
+Two clocks, then: every span a ``with ctx.span(...)`` opens is also a
+``jax.profiler.TraceAnnotation`` named ``hydra.<phase>``, so it lands on
+the profiler's clock beside the device trace whenever a profiler
+collects. ``add_span`` (a wait measured across threads from two explicit
+timestamps: ``queue_wait``, ``dispatch``) stays on the host clock only.
+
+Host phases. Work that no request owns — the LM batcher's step, the
+janitor, pool refills, Python's collector — is named by a second closed
+vocabulary, ``HOST_PHASES``, and opened with ``phase(name, **args)``:
+a ``TraceAnnotation`` named ``hydra.<name>`` and nothing else. These
+record only while a profiler collects; with none, a span costs the
+annotation's construction (no lock, no list, no clock read). hydralint
+HL008 checks ``phase()`` call sites against ``HOST_PHASES`` as it checks
+``span()`` against ``PHASES``. ``install_gc_phase`` hooks the collector
+once (``hydra.gc``, arg ``generation``).
 
 Three consumers:
 
@@ -58,6 +74,7 @@ file I/O, behind a scoped lint disable.
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import math
 import os
@@ -67,6 +84,8 @@ import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.core.metrics import Histogram
 
@@ -83,6 +102,21 @@ PHASES = (
     "compute",
     "body",
 )
+# host phases on the profiler's clock (hydralint HL008 validates every
+# phase() call site against this tuple; kept apart from PHASES so the
+# request aggregation and BENCH_trace.json keep their key shape). The
+# last three run beside the serving loop and hold the GIL or the
+# allocator while they do
+HOST_PHASES = (
+    "lm.step",
+    "lm.admit",
+    "lm.decode",
+    "lm.sync",
+    "runtime.evict_idle",
+    "platform.boot",
+    "gc",
+)
+SPAN_PREFIX = "hydra."
 # computed, never emitted by a span call: the uncovered remainder of the
 # request window (and the arena_acquire claim-kind splits)
 UNATTRIBUTED = "unattributed"
@@ -148,12 +182,44 @@ _NULL_SPAN = _NullSpan()
 NULL_TRACE = _NullTrace()
 
 
+def phase(name: str, **args) -> TraceAnnotation:
+    """The profiler span ``hydra.<name>``; ``args`` are plain ints. Use
+    it as a context manager (HL008); it records only while a profiler
+    collects."""
+    return TraceAnnotation(SPAN_PREFIX + name, **args)
+
+
+_gc_open: Optional[TraceAnnotation] = None
+_gc_install_lock = threading.Lock()
+
+
+def _gc_phase(event: str, info: dict) -> None:
+    # the collector runs on one thread at a time and never nests, so one
+    # slot holds the open span
+    global _gc_open
+    if event == "start":
+        _gc_open = phase("gc", generation=info["generation"])
+        _gc_open.__enter__()
+    elif _gc_open is not None:
+        _gc_open.__exit__(None, None, None)
+        _gc_open = None
+
+
+def install_gc_phase() -> None:
+    """Put Python's collector on the profiler's clock as ``hydra.gc``;
+    idempotent (runtimes call it when built)."""
+    with _gc_install_lock:
+        if _gc_phase not in gc.callbacks:
+            gc.callbacks.append(_gc_phase)
+
+
 class Span:
     """One timed phase inside a request; always used as a context
     manager (``with ctx.span("compute") as sp: ... sp.set(kind=...)``).
     Closing appends the record to the owning trace — an exception
-    propagates, but the span is still recorded."""
-    __slots__ = ("_trace", "name", "attrs", "t0", "t1")
+    propagates, but the span is still recorded. While open it is also the
+    profiler span ``hydra.<name>``."""
+    __slots__ = ("_trace", "name", "attrs", "t0", "t1", "_ann")
 
     def __init__(self, trace: "RequestTrace", name: str):
         self._trace = trace
@@ -161,6 +227,7 @@ class Span:
         self.attrs: Optional[dict] = None
         self.t0 = 0.0
         self.t1 = 0.0
+        self._ann = phase(name)
 
     def set(self, **attrs) -> None:
         if self.attrs is None:
@@ -169,11 +236,13 @@ class Span:
             self.attrs.update(attrs)
 
     def __enter__(self) -> "Span":
+        self._ann.__enter__()
         self.t0 = trace_now()
         return self
 
     def __exit__(self, *exc):
         self.t1 = trace_now()
+        self._ann.__exit__(*exc)
         self._trace._append(self.name, self.t0, self.t1, self.attrs)
         return False
 

@@ -123,5 +123,6 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(lengths.astype(jnp.int32), win, q, k_cache, v_cache)
     return out

@@ -124,6 +124,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(win, qt, kt, vt)
     out = out[:, :, :S, :]
     return jnp.moveaxis(out, 1, 2)

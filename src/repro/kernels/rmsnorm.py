@@ -34,5 +34,6 @@ def rmsnorm(x, w, *, eps: float = 1e-5, interpret: bool = False,
         out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, w)
     return out.reshape(shape)

@@ -117,6 +117,7 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk=64, init_state=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(xt, dtt, dtc, A.astype(jnp.float32), Bm, Cm, s0)
     y = jnp.moveaxis(y, 1, 2)[:, :S]
     if return_state:

@@ -456,6 +456,64 @@ def test_hl008_disable_comment_suppresses(tmp_path):
     assert len(res.suppressed) == 2
 
 
+HOST_STUB = """\
+    PHASES = ("compute",)
+    HOST_PHASES = ("lm.step", "lm.sync")
+"""
+
+
+def test_hl008_flags_unregistered_and_bare_host_phase(tmp_path):
+    src = """\
+        from repro.core import tracing
+        from repro.core.tracing import phase
+
+        def step():
+            with phase("lm.step"):
+                with tracing.phase("lm.sync", tokens=3):
+                    pass
+            with phase("lm.made_up"):          # not in HOST_PHASES
+                pass
+            phase("lm.sync")                     # bare: opens nothing
+    """
+    res = lint_fixture(tmp_path, {"src/repro/core/tracing.py": HOST_STUB,
+                                  "src/sched.py": src}, "HL008")
+    assert sorted(f.detail for f in res.findings) == [
+        "bare-phase:lm.sync:L10", "unknown-host-phase:lm.made_up"]
+    assert "HOST_PHASES" in next(
+        f.message for f in res.findings
+        if f.detail.startswith("unknown"))
+
+
+def test_hl008_request_phase_is_not_a_host_phase(tmp_path):
+    # the two registries stay apart: a request phase is no host phase,
+    # and a host phase is no request span
+    src = """\
+        from repro.core.tracing import phase
+
+        def step(ctx):
+            with phase("compute"):
+                pass
+            with ctx.span("lm.step"):
+                pass
+    """
+    res = lint_fixture(tmp_path, {"src/repro/core/tracing.py": HOST_STUB,
+                                  "src/sched.py": src}, "HL008")
+    assert sorted(f.detail for f in res.findings) == [
+        "unknown-host-phase:compute", "unknown-phase:lm.step"]
+
+
+def test_hl008_host_phase_in_sim_code(tmp_path):
+    src = """\
+        # hydralint: sim-module
+        def step(phase):
+            with phase("lm.step"):
+                pass
+    """
+    res = lint_fixture(tmp_path, {"src/repro/core/tracing.py": HOST_STUB,
+                                  "src/core/sim3.py": src}, "HL008")
+    assert [f.detail for f in res.findings] == ["sim-tracing:phase:L3"]
+
+
 # ---------------------------------------------------------------------------
 # suppression mechanics
 # ---------------------------------------------------------------------------

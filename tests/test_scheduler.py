@@ -147,3 +147,81 @@ def test_slot_reuse_keeps_serving_after_drain(lm_runtime):
         assert not b.pending and not b.active
     finally:
         b.close()
+
+
+# ---------------------------------------------------------------------------
+# host phases on the profiler's clock
+# ---------------------------------------------------------------------------
+def _host_events(trace_dir):
+    """(line index, name, start, end, args) of every ``hydra.`` event on
+    the host plane of the profile written under ``trace_dir``."""
+    import glob
+    import os
+
+    import jax
+    [path] = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                       recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    out = []
+    for plane in pd.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("hydra."):
+                    out.append((i, e.name, e.start_ns, e.end_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda ev: ev[2])
+
+
+def test_step_phases_nest_on_the_profiler_clock(lm_runtime, tmp_path):
+    import gc
+
+    import jax
+    b = ContinuousBatcher(lm_runtime, "t0/lm")
+    try:
+        # a prompt length no other test compiles: the first admission
+        # builds its prefill program
+        futs = [b.submit([1, 2, 3, 4, 5], max_new=3) for _ in range(3)]
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            b.run_until_done(max_steps=50)
+            gc.collect()
+        finally:
+            jax.profiler.stop_trace()
+        assert all(len(f.result()) == 3 for f in futs)
+    finally:
+        b.close()
+    events = _host_events(tmp_path)
+    steps = [e for e in events if e[1] == "hydra.lm.step"]
+    assert len(steps) == b.steps
+    line = steps[0][0]
+    admits_seen = 0
+    for _, _, s0, s1, _ in steps:
+        inside = [e for e in events if e[0] == line and s0 <= e[2]
+                  and e[3] <= s1 and e[1] != "hydra.lm.step"]
+        names = [e[1] for e in inside]
+        admits = [e for e in inside if e[1] == "hydra.lm.admit"]
+        admits_seen += len(admits)
+        assert names.count("hydra.lm.decode") == 1
+        assert names.count("hydra.lm.sync") == 1
+        decode = next(e for e in inside if e[1] == "hydra.lm.decode")
+        sync = next(e for e in inside if e[1] == "hydra.lm.sync")
+        # admissions come first, then the dispatch, then the readback
+        assert all(a[3] <= decode[2] for a in admits)
+        assert decode[3] <= sync[2]
+        assert decode[4]["active"] == sync[4]["tokens"]
+        for a in admits:
+            assert set(a[4]) == {"slot", "prompt_len", "waited_us",
+                                 "compiled"}
+            assert a[4]["prompt_len"] == 5 and a[4]["slot"] in (0, 1)
+            assert a[4]["waited_us"] >= 0
+    assert admits_seen == 3
+    assert [e[4]["compiled"] for e in events
+            if e[1] == "hydra.lm.admit"] == [1, 0, 0]
+    # 2 slots, 3 requests of 3 tokens: the decode calls ran with the
+    # slots in use at each step
+    assert [e[4]["active"] for e in events
+            if e[1] == "hydra.lm.decode"] == [2, 2, 1, 1]
+    gcs = [e for e in events if e[1] == "hydra.gc"]
+    assert any(e[4]["generation"] == 2 for e in gcs)

@@ -315,3 +315,31 @@ def test_export_window_is_bounded():
     s = tracer.summary()
     assert s["export_window_dropped"] == 24
     assert s["finished"] == 40                 # aggregation saw everything
+
+
+def test_sampled_span_is_on_the_profiler_clock(tmp_path):
+    import glob
+    import os
+
+    import jax
+    tracer = Tracer(sample_rate=1.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        ctx = tracer.start_request("f0", "t0")
+        try:
+            with ctx.span("compute"):
+                pass
+            ctx.add_span("dispatch", 0.0, 0.0)    # host clock only
+        finally:
+            ctx.finish()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                       recursive=True)
+    names = [e.name for plane in
+             jax.profiler.ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events
+             if e.name.startswith("hydra.")]
+    assert names == ["hydra.compute"]
+    assert [s[0] for s in ctx.spans] == ["compute", "dispatch"]

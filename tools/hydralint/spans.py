@@ -11,12 +11,15 @@ conservation invariant if call sites follow three rules:
   * Span names come from the closed ``PHASES`` registry in
     ``core/tracing.py`` — an ad-hoc name would aggregate into nothing
     (``summary()`` emits the fixed vocabulary) and break the
-    ``BENCH_trace.json`` key-shape gate.
+    ``BENCH_trace.json`` key-shape gate.  Host phases opened with
+    ``phase(...)`` (profiler spans ``hydra.<name>``) follow the same two
+    rules against the ``HOST_PHASES`` registry: a ``with`` block, and a
+    registered name, which is what the benchmark's readers look for.
   * Sim code (the HL003 scope) never traces: the simulator models
     phases, it does not measure them, and a tracing import there would
     couple the deterministic event loop to wall-clock span timestamps.
 
-The registry is read from the AST of ``src/repro/core/tracing.py``
+Both registries are read from the AST of ``src/repro/core/tracing.py``
 itself (from the project when linted, else from disk under the project
 root) so this checker can never drift from the vocabulary it enforces.
 ``core/tracing.py`` is exempt — it defines the machinery.
@@ -35,13 +38,15 @@ TRACING_PATH = "src/repro/core/tracing.py"
 TRACING_MODULE = "repro.core.tracing"
 # methods of RequestTrace/_NullTrace that take a phase name first
 NAMED_METHODS = ("span", "add_span")
+# the host-phase helper, called bare or as ``tracing.phase``
+HOST_HELPER = "phase"
 
 
-def _load_phases(project: Project):
-    """The ``PHASES`` tuple from core/tracing.py — from the parsed
-    project when tracing.py is among the lint roots, else parsed off
-    disk. None when unavailable (registry checks are skipped rather
-    than guessed)."""
+def _load_phases(project: Project, registry: str = "PHASES"):
+    """The ``registry`` tuple (``PHASES`` or ``HOST_PHASES``) from
+    core/tracing.py — from the parsed project when tracing.py is among
+    the lint roots, else parsed off disk. None when unavailable
+    (registry checks are skipped rather than guessed)."""
     sf = project.by_path.get(TRACING_PATH)
     tree = sf.tree if sf is not None else None
     if tree is None:
@@ -53,7 +58,7 @@ def _load_phases(project: Project):
     for node in tree.body:
         if not isinstance(node, ast.Assign):
             continue
-        if not any(isinstance(t, ast.Name) and t.id == "PHASES"
+        if not any(isinstance(t, ast.Name) and t.id == registry
                    for t in node.targets):
             continue
         if isinstance(node.value, (ast.Tuple, ast.List)):
@@ -63,8 +68,15 @@ def _load_phases(project: Project):
     return None
 
 
+def _is_host_phase_call(node) -> bool:
+    f = node.func
+    return ((isinstance(f, ast.Name) and f.id == HOST_HELPER)
+            or (isinstance(f, ast.Attribute) and f.attr == HOST_HELPER))
+
+
 def check(project: Project) -> list:
     phases = _load_phases(project)
+    host_phases = _load_phases(project, "HOST_PHASES")
     findings = []
     for sf in project.files:
         if sf.path.endswith("core/tracing.py"):
@@ -81,6 +93,10 @@ def check(project: Project) -> list:
                     if isinstance(item.context_expr, ast.Call):
                         with_calls.add(id(item.context_expr))
         for node in ast.walk(sf.tree):
+            if isinstance(node, ast.Call) and _is_host_phase_call(node):
+                findings.extend(_check_host_phase(
+                    sf, node, host_phases, sim, id(node) in with_calls))
+                continue
             if not (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
                     and node.func.attr in NAMED_METHODS):
@@ -112,6 +128,33 @@ def check(project: Project) -> list:
                     f"use add_span(name, t0, t1)",
                     f"bare-span:{name}:L{node.lineno}"))
     return findings
+
+
+def _check_host_phase(sf, node, host_phases, sim: bool,
+                      in_with: bool) -> list:
+    if sim:
+        return [Finding(
+            CODE, sf.path, node.lineno, node.col_offset,
+            "phase() tracing call in sim code — the simulator models "
+            "phases, it must not measure them (HL003 scope)",
+            f"sim-tracing:phase:L{node.lineno}")]
+    out = []
+    name = str_const(node.args[0]) if node.args else None
+    if name is not None and host_phases is not None \
+            and name not in host_phases:
+        out.append(Finding(
+            CODE, sf.path, node.lineno, node.col_offset,
+            f"host phase {name!r} is not in the HOST_PHASES registry "
+            f"(core/tracing.py) — the profiler span hydra.{name} would "
+            f"be charged to nothing",
+            f"unknown-host-phase:{name}"))
+    if not in_with:
+        out.append(Finding(
+            CODE, sf.path, node.lineno, node.col_offset,
+            f"phase({name!r}) must be used as a context manager "
+            f"(with phase(...):) — a bare call opens no profiler span",
+            f"bare-phase:{name}:L{node.lineno}"))
+    return out
 
 
 def _check_sim_imports(sf) -> list:
