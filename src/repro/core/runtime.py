@@ -312,16 +312,17 @@ class HydraRuntime:
         try:
             tok, cache = prefill_exe(spec.params, arena.buffers, prompt,
                                      jnp.int32(0))
-            toks = [int(tok[0])]
             tok = jnp.tile(tok.reshape(1, 1), (spec.slots, 1))
+            rows = [tok]        # (slots, 1) per call; slot 0 is the request
             for _ in range(max_new - 1):
                 tok, cache = func.entry["decode"](spec.params, cache, tok)
-                toks.append(int(tok[0]))
                 tok = tok.reshape(spec.slots, 1)
+                rows.append(tok)
             arena.buffers = cache   # donated in place; hand back the slab
         finally:
             self.arena_pool.release(arena)
-        return toks
+        # one device-to-host transfer for the whole request
+        return jax.device_get(jnp.concatenate(rows, axis=1))[0].tolist()
 
     def _janitor_loop(self):
         while not self._shutdown.is_set():

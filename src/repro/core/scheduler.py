@@ -7,6 +7,10 @@ token buckets (the cgroup CPU-share analog, §3.7).
 admitted request, one ``hydra.lm.decode`` (the decode dispatch) and one
 ``hydra.lm.sync`` (the token readback and retirement). No lock is held
 across any of them: ``submit`` only ever waits for a list append.
+
+Every program call that yields tokens costs exactly one device-to-host
+transfer of its whole token output (``ContinuousBatcher.readbacks``
+counts them); tokens fed back to the device stay on the device.
 """
 from __future__ import annotations
 
@@ -15,6 +19,7 @@ import time
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.tracing import phase
@@ -75,6 +80,7 @@ class ContinuousBatcher:
         self.cache = self.arena.buffers
         self.cur_tok = jnp.zeros((self.slots, 1), jnp.int32)
         self.steps = 0
+        self.readbacks = 0     # device-to-host transfers: steps + admissions
 
     def submit(self, prompt_tokens, max_new: int) -> Future:
         fut: Future = Future()
@@ -98,8 +104,10 @@ class ContinuousBatcher:
                 sp.set_metadata(compiled=int(entry.hits == 0))
                 tok, self.cache = entry.compiled(
                     self.spec.params, self.cache, prompt, jnp.int32(slot))
-                req.emitted.append(int(tok[0]))
-                self.cur_tok = self.cur_tok.at[slot, 0].set(int(tok[0]))
+                # tok is (1,): it lands in cur_tok's row on the device
+                self.cur_tok = self.cur_tok.at[slot].set(tok)
+                req.emitted.append(jax.device_get(tok).item())
+                self.readbacks += 1
             self.active[slot] = req
 
     def step(self) -> int:
@@ -112,11 +120,14 @@ class ContinuousBatcher:
                 tok, self.cache = self.func.entry["decode"](
                     self.spec.params, self.cache, self.cur_tok)
                 self.cur_tok = tok.reshape(self.slots, 1)
+                tok.copy_to_host_async()    # starts the copy; no wait
             self.steps += 1
             done = []
             with phase("lm.sync", tokens=len(self.active)):
+                host_tok = jax.device_get(tok).tolist()
+                self.readbacks += 1
                 for slot, req in self.active.items():
-                    req.emitted.append(int(tok[slot]))
+                    req.emitted.append(host_tok[slot])
                     if len(req.emitted) >= req.max_new:
                         done.append(slot)
                 for slot in done:

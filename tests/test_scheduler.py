@@ -149,6 +149,29 @@ def test_slot_reuse_keeps_serving_after_drain(lm_runtime):
         b.close()
 
 
+@pytest.mark.parametrize("n_requests,max_new", [(1, 2), (2, 4), (5, 3)])
+def test_one_readback_per_program_call(lm_runtime, n_requests, max_new):
+    # every prefill and every decode call costs one device-to-host
+    # transfer, however many slots are active; 5 requests on 2 slots
+    # admit in later steps too
+    b = ContinuousBatcher(lm_runtime, "t0/lm")
+    try:
+        futs = [b.submit([7, 8, 9], max_new=max_new)
+                for _ in range(n_requests)]
+        b.step()
+        admitted = len(b.active) + sum(f.done() for f in futs)
+        assert b.readbacks == b.steps + admitted
+        b.run_until_done(max_steps=50)
+        assert b.readbacks == b.steps + n_requests
+        outs = [f.result() for f in futs]
+    finally:
+        b.close()
+    assert all(len(o) == max_new for o in outs)
+    # plain Python ints, not device or numpy scalars
+    assert all(type(t) is int for o in outs for t in o)
+    assert len({tuple(o) for o in outs}) == 1   # same prompt, same tokens
+
+
 # ---------------------------------------------------------------------------
 # host phases on the profiler's clock
 # ---------------------------------------------------------------------------
