@@ -11,6 +11,10 @@ across any of them: ``submit`` only ever waits for a list append.
 Every program call that yields tokens costs exactly one device-to-host
 transfer of its whole token output (``ContinuousBatcher.readbacks``
 counts them); tokens fed back to the device stay on the device.
+
+A free slot holds cache length 0: decode attention then reads none of its
+KV blocks, and decode leaves its length at 0. ``step`` zeroes the length
+of each slot it retires in one device update, with no host read.
 """
 from __future__ import annotations
 
@@ -21,6 +25,7 @@ from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.tracing import phase
 
@@ -57,6 +62,11 @@ class _Request:
     emitted: list = field(default_factory=list)
 
 
+def _free_slots(cache, freed):
+    """``cache`` with the slots where ``freed`` is true at length 0."""
+    return {**cache, "length": jnp.where(freed, 0, cache["length"])}
+
+
 class ContinuousBatcher:
     """Slot-based continuous batching over one LM function's decode program.
 
@@ -77,7 +87,9 @@ class ContinuousBatcher:
         self._lock = threading.Lock()
         self.arena = runtime.arena_pool.acquire(
             self.func.arena_sig, self.func.arena_factory)
-        self.cache = self.arena.buffers
+        # every slot starts free, whoever held the arena before
+        self.cache = _free_slots(self.arena.buffers,
+                                 np.ones(self.slots, bool))
         self.cur_tok = jnp.zeros((self.slots, 1), jnp.int32)
         self.steps = 0
         self.readbacks = 0     # device-to-host transfers: steps + admissions
@@ -116,7 +128,8 @@ class ContinuousBatcher:
             self._admit()
             if not self.active:
                 return 0
-            with phase("lm.decode", active=len(self.active)):
+            with phase("lm.decode", active=len(self.active),
+                       kv_positions=self._kv_positions()):
                 tok, self.cache = self.func.entry["decode"](
                     self.spec.params, self.cache, self.cur_tok)
                 self.cur_tok = tok.reshape(self.slots, 1)
@@ -134,7 +147,18 @@ class ContinuousBatcher:
                     req = self.active.pop(slot)
                     self.free.append(slot)
                     req.future.set_result(req.emitted)
+                if done:
+                    self.cache = _free_slots(
+                        self.cache, np.isin(np.arange(self.slots), done))
             return len(self.active) + len(done)
+
+    def _kv_positions(self) -> int:
+        """Cache positions the next decode attends, summed over the active
+        slots: each slot's prompt and every token it has emitted (the last
+        is written into the cache by this decode), at most ``max_seq``."""
+        cap = self.spec.max_seq
+        return sum(min(len(r.prompt) + len(r.emitted), cap)
+                   for r in self.active.values())
 
     def run_until_done(self, max_steps: int = 10_000):
         while (self.active or self.pending) and max_steps > 0:

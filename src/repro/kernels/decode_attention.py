@@ -5,8 +5,15 @@ Grid = (B, num_kv_blocks); each instance processes ALL query heads of one
 sequence (the whole q row fits VMEM easily: Hq x hd). The KV axis is the
 innermost "arbitrary" dimension with the online-softmax state in VMEM
 scratch. Per-row valid lengths and the sliding window arrive as
-scalar-prefetch operands (SMEM); the lengths also let fully-invalid KV
-blocks skip their compute, and a traced window compiles like a static one.
+scalar-prefetch operands (SMEM), and a traced window compiles like a
+static one.
+
+Only a row's live blocks are read: the K/V index maps hold each row at
+its last valid block (and a windowed row at its first in-window block),
+and the pipeline issues no copy while consecutive grid steps name the
+same block. The steps past a row's live blocks skip their compute, so a
+row of length 0 (a free serving slot) costs one block fetch and returns
+zeros. Lengths past the cache are read as the whole cache.
 """
 from __future__ import annotations
 
@@ -27,6 +34,9 @@ def _kernel(len_ref, win_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     b = pl.program_id(0)
     ki = pl.program_id(1)
     length = len_ref[b]
+    live = ki * bk < length
+    if windowed:
+        live &= (ki + 1) * bk > length - win_ref[0]
 
     @pl.when(ki == 0)
     def _init():
@@ -34,7 +44,7 @@ def _kernel(len_ref, win_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(ki * bk < length)
+    @pl.when(live)
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale         # (Hq, hd)
         k = k_ref[0].astype(jnp.float32)                 # (bk, Hkv, hd)
@@ -73,6 +83,17 @@ def _kernel(len_ref, win_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                     jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
+def _kv_block(b, j, lens, win, *, bk, nk, windowed):
+    """Grid step (b, j) reads KV block j held to row b's live blocks:
+    from the window's first block (windowed) to the last valid one."""
+    length = lens[b]
+    last = jnp.clip(jax.lax.div(length - 1, bk), 0, nk - 1)
+    if windowed:
+        first = jnp.clip(jax.lax.div(length - win[0], bk), 0, last)
+        j = jnp.maximum(j, first)
+    return b, jnp.minimum(j, last), 0, 0
+
+
 def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
                      scale=None, interpret=False, block_k=None):
     """q (B,Hq,hd), k/v cache (B,S,Hkv,hd), lengths (B,) -> (B,Hq,hd).
@@ -94,9 +115,10 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
         k_cache, v_cache = jnp.pad(k_cache, pad), jnp.pad(v_cache, pad)
     nk = s_pad // bk
 
-    kernel = functools.partial(_kernel, scale=scale,
-                               windowed=window is not None,
+    windowed = window is not None
+    kernel = functools.partial(_kernel, scale=scale, windowed=windowed,
                                bk=bk, nk=nk, group=group)
+    kv_block = functools.partial(_kv_block, bk=bk, nk=nk, windowed=windowed)
     win = jnp.asarray(0 if window is None else window, jnp.int32).reshape(1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -104,10 +126,8 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
         grid=(B, nk),
         in_specs=[
             pl.BlockSpec((1, Hq, hd), lambda b, j, lens, w: (b, 0, 0)),
-            pl.BlockSpec((1, bk, Hkv, hd),
-                         lambda b, j, lens, w: (b, j, 0, 0)),
-            pl.BlockSpec((1, bk, Hkv, hd),
-                         lambda b, j, lens, w: (b, j, 0, 0)),
+            pl.BlockSpec((1, bk, Hkv, hd), kv_block),
+            pl.BlockSpec((1, bk, Hkv, hd), kv_block),
         ],
         out_specs=pl.BlockSpec((1, Hq, hd), lambda b, j, lens, w: (b, 0, 0)),
         scratch_shapes=[
@@ -124,5 +144,5 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="decode_attention",
-    )(lengths.astype(jnp.int32), win, q, k_cache, v_cache)
+    )(jnp.clip(lengths.astype(jnp.int32), 0, S), win, q, k_cache, v_cache)
     return out

@@ -87,8 +87,10 @@ def decode_attention_ref(
     valid = kpos < lengths[:, None]                     # (B, S)
     if window is not None:
         valid &= kpos > (lengths[:, None] - 1 - window)
-    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
-    p = jax.nn.softmax(logits, axis=-1)
+    valid = valid[:, None, None, :]
+    logits = jnp.where(valid, logits, NEG_INF)
+    # a row with no valid key (length 0: a free slot) attends nothing
+    p = jnp.where(valid, jax.nn.softmax(logits, axis=-1), 0.0)
     out = jnp.einsum("bhgk,bkhd->bhgd", p.astype(q.dtype), v_cache,
                      preferred_element_type=jnp.float32)
     return out.reshape(B, Hq, hd).astype(q.dtype)

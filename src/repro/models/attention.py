@@ -69,11 +69,13 @@ def attention_decode(p, x1, cfg, k_cache, v_cache, lengths, window=None):
     """Single-token decode.
 
     x1: (B, 1, D); k_cache/v_cache: (B, S, Hkv, hd); lengths: (B,) valid
-    entries per row. Returns (out (B,1,D), new_k_cache, new_v_cache).
+    entries per row, 0 for a free row, which attends nothing (its output
+    is zero). Returns (out (B,1,D), new_k_cache, new_v_cache).
     """
     B = x1.shape[0]
     q, k, v = _project_qkv(p, x1, cfg)            # q (B,1,Hq,hd)
     pos = lengths.astype(jnp.int32)
+    attended = jnp.where(pos > 0, pos + 1, 0)     # the new token's too
     q = rotary(q, pos[:, None], cfg.rope_theta)
     k = rotary(k, pos[:, None], cfg.rope_theta)
 
@@ -105,11 +107,11 @@ def attention_decode(p, x1, cfg, k_cache, v_cache, lengths, window=None):
                 c, (st, 0, 0), (w, c.shape[1], c.shape[2]))
         k_eff = jax.vmap(win)(k_cache, start)
         v_eff = jax.vmap(win)(v_cache, start)
-        len_eff = jnp.minimum(lengths + 1, w)
+        len_eff = jnp.minimum(attended, w)
         out = ops.decode_attention(q[:, 0], k_eff, v_eff, len_eff,
                                    window=None)
     else:
-        out = ops.decode_attention(q[:, 0], k_cache, v_cache, lengths + 1,
+        out = ops.decode_attention(q[:, 0], k_cache, v_cache, attended,
                                    window=window)
     out = out.reshape(B, 1, cfg.n_heads * cfg.resolved_head_dim)
     out = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(x1.dtype))

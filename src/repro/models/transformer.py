@@ -270,6 +270,13 @@ def prefill(params, cfg, tokens=None, embeds=None, *, n_groups: int = 1,
 # ---------------------------------------------------------------------------
 # decode: one token against the cache
 # ---------------------------------------------------------------------------
+def _advance(lengths):
+    """Each live row's length grows by the decoded token; a free row
+    (length 0) stays at 0, so it never attends and never outgrows the
+    cache however long it waits."""
+    return jnp.where(lengths > 0, lengths + 1, 0)
+
+
 def decode_step(params, cfg, cache, tokens=None, embeds=None,
                 *, n_groups: int = 1, unroll: bool = False):
     """tokens (B,1) / embeds (B,1,D) -> (logits (B,V), new cache)."""
@@ -285,7 +292,8 @@ def decode_step(params, cfg, cache, tokens=None, embeds=None,
         h, (conv, state) = _scan(
             body, h, (params["layers"], cache["conv"], cache["state"]),
             unroll)
-        new_cache = {"conv": conv, "state": state, "length": lengths + 1}
+        new_cache = {"conv": conv, "state": state,
+                     "length": _advance(lengths)}
         return _unembed(params, cfg, h)[:, 0], new_cache
 
     if cfg.family == "hybrid":
@@ -319,7 +327,7 @@ def decode_step(params, cfg, cache, tokens=None, embeds=None,
         conv = conv.reshape((-1,) + conv.shape[2:])
         state = state.reshape((-1,) + state.shape[2:])
         new_cache = {"conv": conv, "state": state, "k": kc, "v": vc,
-                     "length": lengths + 1}
+                     "length": _advance(lengths)}
         return _unembed(params, cfg, h)[:, 0], new_cache
 
     windows = layer_windows(cfg, static=unroll)
@@ -341,5 +349,5 @@ def decode_step(params, cfg, cache, tokens=None, embeds=None,
     h, (kc, vc) = _scan(body, h,
                         (params["layers"], windows, cache["k"], cache["v"]),
                         unroll)
-    new_cache = {"k": kc, "v": vc, "length": lengths + 1}
+    new_cache = {"k": kc, "v": vc, "length": _advance(lengths)}
     return _unembed(params, cfg, h)[:, 0], new_cache

@@ -1,8 +1,9 @@
 """The main path's Pallas kernels compile for a TPU v5e at the widths the
-chip serves (qwen2.5-3b; gemma3-1b's hd-256 decode; mamba2-780m's SSD
-scan). Nothing runs: the TPU compiler, which is installed without a chip,
-compiles for a described v5e and refuses what the chip would refuse —
-misaligned blocks, VMEM overflows — which interpret-mode tests cannot see.
+chip serves (qwen2.5-3b; gemma3-1b's hd-256 decode; an hd-64 decode as in
+granite-4.0-h's GQA layers; mamba2-780m's SSD scan). Nothing runs: the
+TPU compiler, which is installed without a chip, compiles for a described
+v5e and refuses what the chip would refuse — misaligned blocks, VMEM
+overflows — which interpret-mode tests cannot see.
 
 The topology is described inside a fixture, never at import time: only
 one process may load the TPU library, and every test worker imports this
@@ -87,6 +88,21 @@ def test_decode_attention(one_chip, Hq, Hkv, hd, window):
                          ((8, 1024, Hkv, hd), BF16),
                          ((8, 1024, Hkv, hd), BF16), ((8,), jnp.int32),
                          *extra)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,hd,S", [
+    (16, 16, 2, 128, 1536),  # qwen2.5-3b as served: 16 slots x 1536
+    (8, 32, 8, 64, 4352),    # granite-4.0-h's NoPE GQA layers: hd 64
+])
+def test_decode_attention_live_blocks_served_shapes(one_chip, B, Hq, Hkv,
+                                                    hd, S):
+    """The K/V index maps that stop at each row's last live block read
+    the prefetched lengths on the scalar core: they compile at the
+    served cache shapes."""
+    text = compiled_text(decode_attention, one_chip, ((B, Hq, hd), BF16),
+                         ((B, S, Hkv, hd), BF16), ((B, S, Hkv, hd), BF16),
+                         ((B,), jnp.int32))
     assert "tpu_custom_call" in text
 
 

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import _kv_block, decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssd_scan import ssd_scan
@@ -57,6 +57,58 @@ def test_decode_attention(B, S, Hq, Hkv, hd, window, dtype):
     want = ref.decode_attention_ref(q, k, v, lengths, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **tol(dtype))
+
+
+# a row's length against a 256-position cache read in 64-key blocks: free
+# (0), one key, a block boundary and one past it, the whole cache, and past
+# it (the kernel reads the whole cache, never a block beyond it)
+LEN_S, LEN_BK = 256, 64
+
+
+@pytest.mark.parametrize("window", [None, 96])
+@pytest.mark.parametrize("length", [0, 1, LEN_BK, LEN_BK + 1, LEN_S,
+                                    LEN_S + 300])
+def test_decode_attention_row_lengths(length, window):
+    ks = jax.random.split(KEY, 3)
+    B, Hq, Hkv, hd = 3, 4, 2, 64
+    q = jax.random.normal(ks[0], (B, Hq, hd))
+    k = jax.random.normal(ks[1], (B, LEN_S, Hkv, hd))
+    v = jax.random.normal(ks[2], (B, LEN_S, Hkv, hd))
+    lengths = jnp.array([length, 37, 200], jnp.int32)
+    out = decode_attention(q, k, v, lengths, window=window, interpret=True,
+                           block_k=LEN_BK)
+    want = ref.decode_attention_ref(q, k, v, jnp.minimum(lengths, LEN_S),
+                                    window=window)
+    np.testing.assert_allclose(out, want, **tol(jnp.float32))
+    assert np.isfinite(np.asarray(out)).all()
+    if length == 0:
+        assert not np.asarray(out[0]).any()
+
+
+@pytest.mark.parametrize("window", [None, 96])
+def test_decode_attention_fetches_only_live_blocks(window):
+    """The K/V index maps name each row's live blocks in order and repeat
+    the last one, and the pipeline copies a block only when the named
+    block changes: a row of length L costs the blocks holding its window
+    of keys and a free row one block. No length, even one past the cache
+    (the kernel clips those to it), names a block outside the cache."""
+    nk = LEN_S // LEN_BK
+    win = jnp.array([0 if window is None else window], jnp.int32)
+
+    def named(length):
+        lens = jnp.array([length], jnp.int32)
+        return [int(_kv_block(0, j, lens, win, bk=LEN_BK, nk=nk,
+                              windowed=window is not None)[1])
+                for j in range(nk)]
+
+    for length in [0, 1, 63, 64, 65, 150, 200, 255, 256]:
+        got = named(length)
+        first = max(length - window, 0) // LEN_BK if window else 0
+        live = list(range(first, (length - 1) // LEN_BK + 1)) or [0]
+        assert got == sorted(got), (length, got)
+        assert sorted(set(got)) == live, (length, got)
+    for length in [257, 556, 2**31 - 1]:
+        assert all(0 <= i < nk for i in named(length)), length
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

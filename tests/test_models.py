@@ -36,6 +36,28 @@ def make_batch(cfg, B, S, rng):
     return batch
 
 
+def split_last(cfg, full):
+    """(prefill inputs, decode inputs): all but the last position, and
+    the last (a VLM's image embeddings all go to the prefill)."""
+    key = "embeds" if cfg.family == "audio" else "tokens"
+    pre = dict(full)
+    pre[key] = full[key][:, :-1]
+    return pre, {key: full[key][:, -1:]}
+
+
+def grow_kv(cache, n):
+    """``cache`` with ``n`` empty positions appended to its KV slabs, so
+    decode has room."""
+    cache = dict(cache)
+    for key in ("k", "v"):
+        if key in cache:
+            kv = cache[key]
+            cache[key] = jnp.concatenate(
+                [kv, jnp.zeros(kv.shape[:2] + (n,) + kv.shape[3:],
+                               kv.dtype)], axis=2)
+    return cache
+
+
 @pytest.mark.parametrize("arch", ARCHS)
 def test_smoke_forward_and_train_step(arch, rng):
     cfg = get_config(arch).reduced()
@@ -73,31 +95,40 @@ def test_prefill_decode_matches_forward(arch, rng):
         lambda p, b: tf.forward(p, cfg, tokens=b.get("tokens"),
                                 embeds=b.get("embeds")))(params, full)
 
-    pre = dict(full)
-    if cfg.family == "audio":
-        pre["embeds"] = full["embeds"][:, :S]
-        dec_in = {"embeds": full["embeds"][:, S:S + 1]}
-    elif cfg.family == "vlm":
-        pre["tokens"] = full["tokens"][:, :-1]
-        dec_in = {"tokens": full["tokens"][:, -1:]}
-    else:
-        pre["tokens"] = full["tokens"][:, :S]
-        dec_in = {"tokens": full["tokens"][:, S:S + 1]}
-
+    pre, dec_in = split_last(cfg, full)
     last_logits, cache = jax.jit(prog.prefill)(params, pre)
     np.testing.assert_allclose(last_logits, logits_full[:, S - 1],
                                atol=3e-5, rtol=3e-5)
-    # grow kv slabs so decode has room
-    for key in ("k", "v"):
-        if key in cache:
-            kv = cache[key]
-            cache[key] = jnp.concatenate(
-                [kv, jnp.zeros(kv.shape[:2] + (4,) + kv.shape[3:],
-                               kv.dtype)], axis=2)
+    cache = grow_kv(cache, 4)
     dec_logits, new_cache = jax.jit(prog.decode_step)(params, cache, dec_in)
     np.testing.assert_allclose(dec_logits, logits_full[:, -1],
                                atol=3e-5, rtol=3e-5)
     assert int(new_cache["length"][0]) == S + 1
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_decode_free_row_stays_at_length_zero(arch, rng):
+    """A row at length 0 (a free serving slot) decodes to finite logits
+    and stays at 0; the live row beside it advances by one and still
+    matches the cacheless forward."""
+    cfg = dataclasses.replace(get_config(arch).reduced(), dtype="float32")
+    prog = ModelProgram(cfg, remat=False)
+    params = prog.init(rng)
+    B, S = 2, 16
+    full = make_batch(cfg, B, S + 1, rng)
+    full.pop("labels")
+    logits_full, _ = jax.jit(
+        lambda p, b: tf.forward(p, cfg, tokens=b.get("tokens"),
+                                embeds=b.get("embeds")))(params, full)
+    pre, dec_in = split_last(cfg, full)
+    _, cache = jax.jit(prog.prefill)(params, pre)
+    cache = grow_kv(cache, 4)
+    cache["length"] = cache["length"].at[1].set(0)
+    dec_logits, new_cache = jax.jit(prog.decode_step)(params, cache, dec_in)
+    assert new_cache["length"].tolist() == [S + 1, 0]
+    assert np.isfinite(np.asarray(dec_logits)).all()
+    np.testing.assert_allclose(dec_logits[0], logits_full[0, -1],
+                               atol=3e-5, rtol=3e-5)
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -4,6 +4,7 @@ ordering (FIFO pending queue, slot reuse)."""
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from repro.core.scheduler import ContinuousBatcher, TokenBucket
@@ -173,6 +174,84 @@ def test_one_readback_per_program_call(lm_runtime, n_requests, max_new):
 
 
 # ---------------------------------------------------------------------------
+# free slots hold cache length 0
+# ---------------------------------------------------------------------------
+def _lengths(b):
+    return np.asarray(b.cache["length"]).tolist()
+
+
+def _expected_lengths(b):
+    """A live slot holds its prompt and every emitted token but the last;
+    a free slot holds 0."""
+    want = [0] * b.slots
+    for slot, req in b.active.items():
+        want[slot] = len(req.prompt) + len(req.emitted) - 1
+    return want
+
+
+def test_retired_slot_holds_length_zero(lm_runtime):
+    b = ContinuousBatcher(lm_runtime, "t0/lm")
+    try:
+        short = b.submit([1, 2, 3, 4], max_new=3)
+        long = b.submit([5, 6, 7], max_new=9)
+        b.step()
+        assert _lengths(b) == [5, 4]
+        b.step()                     # the short request retires here
+        assert short.done() and list(b.active) == [1]
+        assert _lengths(b) == [0, 5]
+        for n in range(6, 11):       # the free slot stays at 0
+            b.step()
+            assert _lengths(b) == [0, n]
+        b.step()                     # the long request's ninth token
+        assert long.done() and _lengths(b) == [0, 0]
+    finally:
+        b.close()
+
+
+def test_free_slot_never_outgrows_cache(lm_runtime):
+    # one request at a time on 2 slots: FIFO reuse leaves each slot free
+    # for a whole request, over 200 decode calls in all; a free length
+    # that kept growing would pass max_seq (64) within two requests
+    b = ContinuousBatcher(lm_runtime, "t0/lm")
+    max_seq = lm_runtime.registry.get("t0/lm").spec.max_seq
+    try:
+        for i in range(6):
+            fut = b.submit([3 + i, 4, 5], max_new=40)
+            while not fut.done():
+                b.step()
+                lengths = _lengths(b)
+                assert max(lengths) <= max_seq, (b.steps, lengths)
+                assert lengths == _expected_lengths(b), b.steps
+            assert len(fut.result()) == 40
+        assert b.steps == 6 * 39
+    finally:
+        b.close()
+
+
+def test_live_tokens_match_every_slot_busy(lm_runtime):
+    prompts = [[11, 12, 13, 14], [21, 22, 23]]
+    b = ContinuousBatcher(lm_runtime, "t0/lm")
+    try:
+        busy = [b.submit(p, max_new=6) for p in prompts]
+        b.run_until_done(max_steps=50)
+        busy = [f.result() for f in busy]
+        # each alone, beside a free slot; then the second joins late
+        alone = []
+        for p in prompts:
+            alone.append(b.submit(p, max_new=6))
+            b.run_until_done(max_steps=50)
+        first = b.submit(prompts[0], max_new=6)
+        b.step()
+        b.step()
+        late = b.submit(prompts[1], max_new=6)
+        b.run_until_done(max_steps=50)
+    finally:
+        b.close()
+    assert [f.result() for f in alone] == busy
+    assert [first.result(), late.result()] == busy
+
+
+# ---------------------------------------------------------------------------
 # host phases on the profiler's clock
 # ---------------------------------------------------------------------------
 def _host_events(trace_dir):
@@ -246,5 +325,8 @@ def test_step_phases_nest_on_the_profiler_clock(lm_runtime, tmp_path):
     # slots in use at each step
     assert [e[4]["active"] for e in events
             if e[1] == "hydra.lm.decode"] == [2, 2, 1, 1]
+    # positions attended: prompt 5 plus the tokens emitted, per slot
+    assert [e[4]["kv_positions"] for e in events
+            if e[1] == "hydra.lm.decode"] == [12, 14, 6, 7]
     gcs = [e for e in events if e[1] == "hydra.gc"]
     assert any(e[4]["generation"] == 2 for e in gcs)
